@@ -41,7 +41,9 @@ def run_one(arch, shape, mesh, extra=(), tag="", timeout=3600):
     t0 = time.time()
     try:
         r = subprocess.run(cmd, capture_output=True, text=True, timeout=timeout,
-                           env={**os.environ, "PYTHONPATH": "src"},
+                           env={**os.environ, "PYTHONPATH": "src",
+                                # compile-only child: keep it off the chip
+                                "JAX_PLATFORMS": "cpu"},
                            cwd=os.path.join(os.path.dirname(__file__), ".."))
     except subprocess.TimeoutExpired:
         print(f"[TIMEOUT {timeout}s] {name}")

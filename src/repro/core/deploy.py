@@ -34,6 +34,7 @@ from jax.sharding import PartitionSpec as P
 from ..core.compression import (quantize_decode, quantize_encode,
                                 wire_index_bits)
 from ..core.pytree import tree_map
+from ..kernels import ops as _ops
 from ..kernels.compress_pipeline import quant_pipeline
 from ..kernels.pack_bits import _TILE_VALS, pack_bits, unpack_bits
 from ..models.transformer import init_params, lm_loss
@@ -111,7 +112,8 @@ class DeployFedLT:
             lambda a: jnp.broadcast_to(a[None], (n_agents,) + a.shape).copy(), t)
         zeros = lambda t: tree_map(jnp.zeros_like, t)
         xa = stack(p0)
-        return DeployState(x=xa, z=xa, c_up=zeros(xa), y_hat=p0,
+        # z gets buffers of its own so that a round may donate the state
+        return DeployState(x=xa, z=stack(p0), c_up=zeros(xa), y_hat=p0,
                            c_down=zeros(p0), k=jnp.zeros((), jnp.int32))
 
     # -- one round ----------------------------------------------------------
@@ -170,7 +172,7 @@ class DeployFedLT:
         # ---- uplink: quantize + EF; integer tensor crosses the slow link --
         if self.compress:
             bits = self.wire_word_bits
-            interp = jax.default_backend() != "tpu"
+            interp = _ops._interpret()
 
             def _fused_uplink(z, c, **kw):
                 with jax.named_scope("fedlt.uplink.fused_pipeline"):

@@ -136,8 +136,6 @@ class FedLT:
         :func:`repro.launch.sharding.fleet_mesh` which returns ``None``
         on a single device (fall back to :meth:`round` then).
         """
-        from jax.experimental.shard_map import shard_map
-
         fleet = mesh.axis_names[0]
         n_dev = mesh.shape[fleet]
         if n_agents % n_dev:
@@ -178,11 +176,11 @@ class FedLT:
                     k + 1, n_active)
 
         Pf, Pr = P(fleet), P()
-        sharded = shard_map(
-            body, mesh,
+        sharded = jax.shard_map(
+            body, mesh=mesh,
             in_specs=(Pf, Pf, Pf, Pf, Pr, Pr, Pf, Pf, Pr, Pf),
             out_specs=(Pf, Pf, Pf, Pf, Pr, Pr, Pr),
-            check_rep=False)
+            check_vma=False)
 
         def round_fn(state: FedLTState, data, active, key):
             k_down, k_up = jax.random.split(key)

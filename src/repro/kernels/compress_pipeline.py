@@ -49,6 +49,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .pack_bits import GROUP, LANES, R, _TILE_VALS, _check_bits
+from .quantize_ef import inv_delta
 
 __all__ = ["quant_pipeline", "sign_pipeline", "pipeline_tile_values"]
 
@@ -71,11 +72,13 @@ def _quant_kernel(msg_ref, cache_ref, words_ref, newc_ref, *,
     cache = cache_ref[...].astype(jnp.float32)
     delta = (vmax - vmin) / levels
     corrected = msg + cache
-    idx = jnp.floor((jnp.clip(corrected, vmin, vmax) - vmin) / delta + 0.5)
+    idx = jnp.floor((jnp.clip(corrected, vmin, vmax) - vmin) * inv_delta(delta)
+                    + 0.5)
     idx = jnp.clip(idx, 0.0, float(levels))
     decoded = idx * delta + vmin
     newc_ref[...] = (corrected - decoded).astype(newc_ref.dtype)
-    _pack_planes(idx.astype(jnp.uint32), words_ref, bits)
+    # Mosaic has no f32 -> uint32 cast; the index is in [0, levels]
+    _pack_planes(idx.astype(jnp.int32).astype(jnp.uint32), words_ref, bits)
 
 
 def _sign_kernel(msg_ref, cache_ref, scale_ref, words_ref, newc_ref):

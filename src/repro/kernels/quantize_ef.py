@@ -16,10 +16,23 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 BLOCK_M = 256
 BLOCK_N = 128
+
+
+def inv_delta(delta: float) -> float:
+    """``1/delta`` rounded as XLA rounds it.
+
+    XLA rewrites ``x / c`` for a constant ``c`` into ``x * (1/c)`` with the
+    reciprocal taken in f32, so the jnp oracles in :mod:`.ref` multiply.
+    Mosaic keeps a true division, which can land one ulp away and flip a
+    level index at a rounding tie; multiplying by this same constant keeps
+    the kernels bit-identical to the oracles on the chip as on the CPU.
+    """
+    return float(np.float32(1.0) / np.float32(delta))
 
 
 def _kernel(msg_ref, cache_ref, wire_ref, newc_ref, *, levels, vmin, vmax):
@@ -27,10 +40,12 @@ def _kernel(msg_ref, cache_ref, wire_ref, newc_ref, *, levels, vmin, vmax):
     cache = cache_ref[...].astype(jnp.float32)
     delta = (vmax - vmin) / levels
     corrected = msg + cache
-    idx = jnp.floor((jnp.clip(corrected, vmin, vmax) - vmin) / delta + 0.5)
+    idx = jnp.floor((jnp.clip(corrected, vmin, vmax) - vmin) * inv_delta(delta)
+                    + 0.5)
     idx = jnp.clip(idx, 0.0, float(levels))
     decoded = idx * delta + vmin
-    wire_ref[...] = idx.astype(wire_ref.dtype)
+    # Mosaic has no f32 -> uint8/uint16 cast; the index is in [0, levels]
+    wire_ref[...] = idx.astype(jnp.int32).astype(wire_ref.dtype)
     newc_ref[...] = (corrected - decoded).astype(newc_ref.dtype)
 
 

@@ -50,6 +50,19 @@ def _mask_value(scores, q_pos, k_pos, window: Optional[int]):
     return jnp.where(ok, scores, NEG_INF)
 
 
+def _running_max(m, s):
+    """Online-softmax shift: the running row max of the scores.
+
+    The output does not depend on the shift, so no gradient flows through
+    it (as in ``jax.nn.logsumexp``).  Differentiating ``jnp.max`` divides
+    by the count of entries equal to the max; where XLA computes the
+    scores once for the max and again for that comparison, the two can
+    round differently on a TPU, the count is 0, and every query and key
+    gradient becomes NaN.
+    """
+    return jnp.maximum(m, jax.lax.stop_gradient(jnp.max(s, axis=-1)))
+
+
 def _softcap(scores, cap: Optional[float]):
     if cap is None:
         return scores
@@ -120,7 +133,7 @@ def attention_chunked_unrolled(q, k, v, q_pos, k_pos, *, window=None,
                 jnp.float32) * scale
             s = _softcap(s, softcap)
             s = _mask_value(s, qp, kp, window)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            m_new = _running_max(m, s)
             p = jnp.exp(s - m_new[..., None])
             corr = jnp.exp(m - m_new)
             l = l * corr + jnp.sum(p, axis=-1)
@@ -189,7 +202,7 @@ def attention_chunked(q, k, v, q_pos, k_pos, *, window=None, softcap=None,
             s = _mask_value(s, qp_blk, kp_blk, window)
             # mask out-of-range chunk visits entirely
             s = jnp.where((start + j) < hi, s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            m_new = _running_max(m, s)
             p = jnp.exp(s - m_new[..., None])
             corr = jnp.exp(m - m_new)
             l_new = l * corr + jnp.sum(p, axis=-1)

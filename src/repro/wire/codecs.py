@@ -39,6 +39,7 @@ import numpy as np
 from ..core.compression import (Compressor, Identity, RandD, ScaledSign,
                                 TopK, UniformQuantizer, quantize_decode,
                                 quantize_encode, wire_index_bits)
+from ..kernels import ops as _ops
 from ..kernels.pack_bits import logical_words, pack_bits, unpack_bits
 from .message import LeafWire, WireMessage, leaf_header_nbytes
 
@@ -46,7 +47,7 @@ from .message import LeafWire, WireMessage, leaf_header_nbytes
 def _interpret(flag: Optional[bool]) -> bool:
     if flag is not None:
         return flag
-    return jax.default_backend() != "tpu"
+    return _ops._interpret()
 
 
 def index_bits(n: int) -> int:

@@ -17,6 +17,7 @@ KERNEL_MODULES = {
     "test_erasure_kernel",
     "test_attention_backends",
     "test_ssm_oracles",
+    "test_tpu_compile",
 }
 SIMWIRE_MODULES = {
     "test_sim_contacts",

@@ -107,3 +107,37 @@ def test_ef_caches_bounded():
     # when messages are in-range (EF never accumulates unboundedly in-range)
     for leaf in jax.tree_util.tree_leaves(state.c_up):
         assert float(jnp.max(jnp.abs(leaf))) < delta + 1e-3
+
+
+def test_train_donates_state():
+    """``launch.train.train`` compiles the round with the state donated:
+    the new state takes over the old one's buffers, which is what lets a
+    full-width round fit one chip."""
+    from repro.launch.train import train
+
+    out = train(CFG, rounds=2, agents=2, batch=1, seq=32, pack_wire=True)
+    n_state = 3 * 2 * out["n_params"] + 2 * out["n_params"]   # x,z,c_up + ŷ,c_down
+    assert out["memory"].alias_size_in_bytes >= 4 * n_state
+    assert len(out["losses"]) == len(out["seconds"]) == 2
+    assert all(jnp.isfinite(x) for x in out["losses"])
+
+
+def test_compile_cache_location(monkeypatch):
+    from pathlib import Path
+
+    from repro.launch import train as launch_train
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert launch_train.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir is None
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = launch_train.enable_compile_cache()
+        checkout = Path(__file__).resolve().parents[1]
+        assert path == str(checkout / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

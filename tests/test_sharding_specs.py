@@ -11,8 +11,8 @@ from repro.configs import ARCHS
 from repro.launch.sharding import batch_specs, cache_specs, param_specs
 from repro.models.transformer import init_cache, init_params
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH3 = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _shapes(cfg):
