@@ -81,9 +81,15 @@ class FedLT:
         """
         k_down, k_up = jax.random.split(key)
 
+        # jax.named_scope: the stages' names in the HLO metadata and in
+        # jax.profiler traces, as DeployFedLT.round_step names them (no
+        # change to the compiled code)
         # ---- coordinator: aggregate + downlink EF (paper lines 3-5) ----
-        y_mean = tree_mean_axis0(state.z_hat)
-        y_wire, c_down_new = self.downlink.send(k_down, y_mean, state.c_down)
+        with jax.named_scope("fedlt.aggregate"):
+            y_mean = tree_mean_axis0(state.z_hat)
+        with jax.named_scope("fedlt.downlink"):
+            y_wire, c_down_new = self.downlink.send(k_down, y_mean,
+                                                    state.c_down)
 
         # ---- agents: local training (paper lines 8-14), vmapped ----
         grad_fn = jax.grad(self.loss)
@@ -95,7 +101,8 @@ class FedLT:
             z_new = tree_map(lambda z, xn, y: z + 2.0 * (xn - y), z_i, w, y_wire)
             return w, z_new
 
-        x_new, z_new = jax.vmap(agent_update)(state.x, state.z, data)
+        with jax.named_scope("fedlt.local_train"):
+            x_new, z_new = jax.vmap(agent_update)(state.x, state.z, data)
 
         # partial participation: inactive agents keep x, z (paper line 18)
         x_next = tree_where_mask(active, x_new, state.x)
@@ -103,14 +110,15 @@ class FedLT:
 
         # ---- uplink EF + transmit (paper lines 15-16), per agent ----
         n_agents = active.shape[0]
-        if self.fused_uplink and self.uplink.fusable():
-            # one kernel dispatch per leaf over the full agent stack
-            wire, c_up_new = self.uplink.send_fused(z_next, state.c_up)
-        else:
-            up_keys = jax.random.split(k_up, n_agents)
-            wire, c_up_new = jax.vmap(
-                lambda kk, m, c: self.uplink.send(kk, m, c))(
-                    up_keys, z_next, state.c_up)
+        with jax.named_scope("fedlt.uplink"):
+            if self.fused_uplink and self.uplink.fusable():
+                # one kernel dispatch per leaf over the full agent stack
+                wire, c_up_new = self.uplink.send_fused(z_next, state.c_up)
+            else:
+                up_keys = jax.random.split(k_up, n_agents)
+                wire, c_up_new = jax.vmap(
+                    lambda kk, m, c: self.uplink.send(kk, m, c))(
+                        up_keys, z_next, state.c_up)
         c_up_next = tree_where_mask(active, c_up_new, state.c_up)
         z_hat_next = tree_where_mask(active, wire, state.z_hat)
 

@@ -38,6 +38,7 @@ import numpy as np
 from ..constellation.links import message_bytes
 from ..faults import quorum_close_time
 from ..obs.trace import active as _obs_active
+from ..obs.trace import span as obs_span
 from .compression import Compressor
 from .error_feedback import resync_cache
 from .pytree import tree_map, tree_size, tree_split_keys, tree_where_mask
@@ -285,159 +286,154 @@ class SpaceRunner:
                         if lg.error is not None:
                             trc.series("e_K", lg.round, lg.error)
         for k in range(start_k, n_rounds):
-            if trc is None:
-                res = self.engine.run_round(t, msg)
-            else:
-                with trc.span("stage", name="engine.run_round", round=k):
+            with obs_span("runner.round", round=k):
+                with obs_span("runner.schedule"):
                     res = self.engine.run_round(t, msg)
-            t_round0 = t
-            delivered = res.mask
-            attempted = np.zeros_like(delivered)
-            merged = getattr(res, "merged", None)
-            if merged is not None:
-                # in-orbit aggregation: one head delivery stands for every
-                # member it merged — they all trained and their wires all
-                # crossed ISLs, so a lost head wire loses (and, below,
-                # reverts) the whole plane
-                for d in res.deliveries:
-                    attempted[list(merged[d.sat])] = True
-            else:
-                for d in res.deliveries:
-                    attempted[d.sat] = True
-            aborted = getattr(res, "aborted", None)
-            if aborted is not None:
-                # updates destroyed in-orbit with no delivery record
-                # (head-failover collateral): attempted-but-lost
-                attempted = attempted | aborted
-            crashed = getattr(res, "crashed", None)
-            duration = res.duration
-            if self.deadline is not None:
-                # quorum round closing: the coordinator stops waiting at
-                # t_close; anything landing later is a straggler whose
-                # wire (and, with loss_robust, residual) reverts below —
-                # its content folds into the next round via EF
-                landed = [(d.t_done,
-                           len(merged[d.sat]) if merged is not None else 1)
-                          for d in res.deliveries if d.delivered]
-                t_close = quorum_close_time(
-                    t_round0, self.deadline, self.quorum, landed,
-                    int(attempted.sum()))
-                late = np.zeros_like(delivered)
-                for d in res.deliveries:
-                    if d.delivered and d.t_done > t_close:
-                        if merged is not None:
-                            late[list(merged[d.sat])] = True
-                        else:
-                            late[d.sat] = True
-                delivered = delivered & ~late
-                duration = max(t_close - t_round0, 0.0)
-            lost = attempted & ~delivered
-            lossy = bool(lost.any())
-            # with a lossy channel the satellites that transmitted-but-lost
-            # still trained and paid the uplink: they participate in the
-            # round, then the coordinator-side wire is reverted below
-            # (the coordinator can only know what actually landed)
-            active_np = attempted if lossy else delivered
-            if trc is None:
-                state_new, _ = round_fn(state, data, jnp.asarray(active_np),
-                                        keys[k])
-            else:
-                with trc.span("stage", name="alg.round", round=k,
-                              n_active=int(active_np.sum())):
-                    state_new, _ = round_fn(state, data,
-                                            jnp.asarray(active_np), keys[k])
-            # what each satellite actually put on the air this round — for
-            # lost satellites that is the PRE-revert wire, so cohort byte
-            # accounting below must measure this state, not the final one
-            tx_state = state_new
-            if lossy:
-                absorb = self.loss_robust and has_cache
-                state_new = _revert_lost_wires(
-                    state_new, state, wire_field, jnp.asarray(lost),
-                    absorb=absorb)
-                if trc is not None:
-                    # resid_norm: ‖c_up[lost]‖ after the revert — the EF
-                    # content kept telescoping instead of vanishing
-                    lost_idx = np.nonzero(lost)[0]
-                    norm2 = 0.0
-                    if has_cache:
-                        for leaf in jax.tree_util.tree_leaves(state_new.c_up):
-                            arr = np.asarray(leaf[lost_idx], dtype=np.float64)
-                            norm2 += float((arr * arr).sum())
-                    trc.event("ef_revert", round=k, n_lost=int(lost.sum()),
-                              sats=[int(s) for s in lost_idx],
-                              absorb=bool(absorb),
-                              resid_norm=float(np.sqrt(norm2)))
-                    trc.metrics.counter("ef_reverts").add(float(lost.sum()))
-                    trc.series("ef_resid_norm", k, float(np.sqrt(norm2)))
-            if crashed is not None and bool(crashed.any()) and has_cache:
-                # crash semantics: the rebooted sat's memory is gone, so
-                # the erasure revert above (which KEEPS the residual) is
-                # overridden for crashed rows — c_up re-syncs to zero
-                state_new = state_new._replace(
-                    c_up=resync_cache(state_new.c_up, crashed))
-                if trc is not None:
-                    trc.event("ef_resync", round=k,
-                              n_crashed=int(crashed.sum()),
-                              sats=[int(s) for s in np.nonzero(crashed)[0]])
-                    trc.metrics.counter("ef_resyncs").add(
-                        float(crashed.sum()))
-            state = state_new
-            t += duration
-            # bytes_up = what actually crossed the GS links this round —
-            # air bytes, i.e. retransmissions and truncated attempts count
-            if use_cohorts:
-                per_sat = self._cohort_nbytes(tx_state, res.cohorts())
-                if channel is not None:
-                    up_bytes += sum(
-                        per_sat[d.sat] * (d.nbytes_attempted / msg)
-                        for d in res.deliveries)
-                else:
-                    up_bytes += sum(per_sat.values())
-            else:
-                up_bytes += sum(d.nbytes_attempted for d in res.deliveries)
-            isl_bytes += float(getattr(res, "bytes_isl", 0.0))
-            err = (float(error_fn(state))
-                   if error_fn is not None and (k % log_every == 0
-                                                or k == n_rounds - 1) else None)
-            logs.append(RoundLog(k, t, up_bytes, int(delivered.sum()), err,
-                                 n_lost=int(lost.sum()),
-                                 bytes_isl=isl_bytes))
-            if trc is not None:
-                # downlink ledger: the coordinator rebroadcasts the model
-                # to every satellite it scheduled (not modeled by the
-                # engine's uplink timeline, so accounted here)
-                down = trc.metrics.counter("bytes_down")
-                down.add(msg * float(res.scheduled.sum()))
-                plane_kw = ({} if merged is None
-                            else dict(bytes_isl=float(isl_bytes)))
-                trc.event("fl_round", round=k, t0=float(t_round0),
-                          t=float(t), bytes_up=float(up_bytes),
-                          n_active=int(delivered.sum()),
-                          n_lost=int(lost.sum()),
-                          error=err if err == err else None,
-                          mode="sync", **plane_kw)
-                # first-class convergence/byte curves for the run ledger
-                trc.series("bytes_up", k, up_bytes)
-                trc.series("bytes_down", k, down.total)
+                t_round0 = t
+                delivered = res.mask
+                attempted = np.zeros_like(delivered)
+                merged = getattr(res, "merged", None)
                 if merged is not None:
-                    trc.series("bytes_isl_cum", k, isl_bytes)
-                n_att = int(attempted.sum())
-                trc.series("lost_frac", k,
-                           float(lost.sum()) / n_att if n_att else 0.0)
-                # quorum/fault observability: who made it into this
-                # round's aggregate, and what fraction of the attempted
-                # cohort that is (1.0 on a healthy deadline-less round)
-                n_surv = int(delivered.sum())
-                trc.series("survivors", k, float(n_surv))
-                trc.series("quorum_frac", k,
-                           n_surv / n_att if n_att else 1.0)
-                if err is not None and err == err:
-                    trc.series("e_K", k, err)
-            if ckpt is not None and ((k + 1) % ckpt_every == 0
-                                     or k == n_rounds - 1):
-                ckpt.save_round(state, step=k + 1, t=t, up_bytes=up_bytes,
-                                isl_bytes=isl_bytes, logs=logs)
+                    # in-orbit aggregation: one head delivery stands for every
+                    # member it merged — they all trained and their wires all
+                    # crossed ISLs, so a lost head wire loses (and, below,
+                    # reverts) the whole plane
+                    for d in res.deliveries:
+                        attempted[list(merged[d.sat])] = True
+                else:
+                    for d in res.deliveries:
+                        attempted[d.sat] = True
+                aborted = getattr(res, "aborted", None)
+                if aborted is not None:
+                    # updates destroyed in-orbit with no delivery record
+                    # (head-failover collateral): attempted-but-lost
+                    attempted = attempted | aborted
+                crashed = getattr(res, "crashed", None)
+                duration = res.duration
+                if self.deadline is not None:
+                    # quorum round closing: the coordinator stops waiting at
+                    # t_close; anything landing later is a straggler whose
+                    # wire (and, with loss_robust, residual) reverts below —
+                    # its content folds into the next round via EF
+                    landed = [(d.t_done,
+                               len(merged[d.sat]) if merged is not None else 1)
+                              for d in res.deliveries if d.delivered]
+                    t_close = quorum_close_time(
+                        t_round0, self.deadline, self.quorum, landed,
+                        int(attempted.sum()))
+                    late = np.zeros_like(delivered)
+                    for d in res.deliveries:
+                        if d.delivered and d.t_done > t_close:
+                            if merged is not None:
+                                late[list(merged[d.sat])] = True
+                            else:
+                                late[d.sat] = True
+                    delivered = delivered & ~late
+                    duration = max(t_close - t_round0, 0.0)
+                lost = attempted & ~delivered
+                lossy = bool(lost.any())
+                # with a lossy channel the satellites that transmitted-but-lost
+                # still trained and paid the uplink: they participate in the
+                # round, then the coordinator-side wire is reverted below
+                # (the coordinator can only know what actually landed)
+                active_np = attempted if lossy else delivered
+                with obs_span("runner.dispatch"):
+                    state_new, _ = round_fn(state, data, jnp.asarray(active_np),
+                                            keys[k])
+                # what each satellite actually put on the air this round — for
+                # lost satellites that is the PRE-revert wire, so cohort byte
+                # accounting below must measure this state, not the final one
+                tx_state = state_new
+                if lossy:
+                    absorb = self.loss_robust and has_cache
+                    with obs_span("runner.revert"):
+                        state_new = _revert_lost_wires(
+                            state_new, state, wire_field, jnp.asarray(lost),
+                            absorb=absorb)
+                    if trc is not None:
+                        # resid_norm: ‖c_up[lost]‖ after the revert — the EF
+                        # content kept telescoping instead of vanishing
+                        lost_idx = np.nonzero(lost)[0]
+                        norm2 = 0.0
+                        if has_cache:
+                            for leaf in jax.tree_util.tree_leaves(state_new.c_up):
+                                arr = np.asarray(leaf[lost_idx], dtype=np.float64)
+                                norm2 += float((arr * arr).sum())
+                        trc.event("ef_revert", round=k, n_lost=int(lost.sum()),
+                                  sats=[int(s) for s in lost_idx],
+                                  absorb=bool(absorb),
+                                  resid_norm=float(np.sqrt(norm2)))
+                        trc.metrics.counter("ef_reverts").add(float(lost.sum()))
+                        trc.series("ef_resid_norm", k, float(np.sqrt(norm2)))
+                if crashed is not None and bool(crashed.any()) and has_cache:
+                    # crash semantics: the rebooted sat's memory is gone, so
+                    # the erasure revert above (which KEEPS the residual) is
+                    # overridden for crashed rows — c_up re-syncs to zero
+                    with obs_span("runner.revert"):
+                        state_new = state_new._replace(
+                            c_up=resync_cache(state_new.c_up, crashed))
+                    if trc is not None:
+                        trc.event("ef_resync", round=k,
+                                  n_crashed=int(crashed.sum()),
+                                  sats=[int(s) for s in np.nonzero(crashed)[0]])
+                        trc.metrics.counter("ef_resyncs").add(
+                            float(crashed.sum()))
+                state = state_new
+                t += duration
+                # bytes_up = what actually crossed the GS links this round —
+                # air bytes, i.e. retransmissions and truncated attempts count
+                if use_cohorts:
+                    per_sat = self._cohort_nbytes(tx_state, res.cohorts())
+                    if channel is not None:
+                        up_bytes += sum(
+                            per_sat[d.sat] * (d.nbytes_attempted / msg)
+                            for d in res.deliveries)
+                    else:
+                        up_bytes += sum(per_sat.values())
+                else:
+                    up_bytes += sum(d.nbytes_attempted for d in res.deliveries)
+                isl_bytes += float(getattr(res, "bytes_isl", 0.0))
+                err = (float(error_fn(state))
+                       if error_fn is not None and (k % log_every == 0
+                                                    or k == n_rounds - 1) else None)
+                logs.append(RoundLog(k, t, up_bytes, int(delivered.sum()), err,
+                                     n_lost=int(lost.sum()),
+                                     bytes_isl=isl_bytes))
+                if trc is not None:
+                    # downlink ledger: the coordinator rebroadcasts the model
+                    # to every satellite it scheduled (not modeled by the
+                    # engine's uplink timeline, so accounted here)
+                    down = trc.metrics.counter("bytes_down")
+                    down.add(msg * float(res.scheduled.sum()))
+                    plane_kw = ({} if merged is None
+                                else dict(bytes_isl=float(isl_bytes)))
+                    trc.event("fl_round", round=k, t0=float(t_round0),
+                              t=float(t), bytes_up=float(up_bytes),
+                              n_active=int(delivered.sum()),
+                              n_lost=int(lost.sum()),
+                              error=err if err == err else None,
+                              mode="sync", **plane_kw)
+                    # first-class convergence/byte curves for the run ledger
+                    trc.series("bytes_up", k, up_bytes)
+                    trc.series("bytes_down", k, down.total)
+                    if merged is not None:
+                        trc.series("bytes_isl_cum", k, isl_bytes)
+                    n_att = int(attempted.sum())
+                    trc.series("lost_frac", k,
+                               float(lost.sum()) / n_att if n_att else 0.0)
+                    # quorum/fault observability: who made it into this
+                    # round's aggregate, and what fraction of the attempted
+                    # cohort that is (1.0 on a healthy deadline-less round)
+                    n_surv = int(delivered.sum())
+                    trc.series("survivors", k, float(n_surv))
+                    trc.series("quorum_frac", k,
+                               n_surv / n_att if n_att else 1.0)
+                    if err is not None and err == err:
+                        trc.series("e_K", k, err)
+                if ckpt is not None and ((k + 1) % ckpt_every == 0
+                                         or k == n_rounds - 1):
+                    ckpt.save_round(state, step=k + 1, t=t, up_bytes=up_bytes,
+                                    isl_bytes=isl_bytes, logs=logs)
         return state, logs
 
     # -- buffered-async (FedBuff-style) -------------------------------------
@@ -448,14 +444,9 @@ class SpaceRunner:
         wire_field = "z_hat" if hasattr(state, "z_hat") else "m_hat"
 
         trc = _obs_active()       # read once; None ⇒ tracing fully off
-        if trc is None:
+        with obs_span("runner.schedule"):
             records = self.engine.run_async(
                 0.0, msg, n_deliveries=n_rounds * self.buffer_size)
-        else:
-            with trc.span("stage", name="engine.run_async",
-                          n_deliveries=n_rounds * self.buffer_size):
-                records = self.engine.run_async(
-                    0.0, msg, n_deliveries=n_rounds * self.buffer_size)
         # only landed updates feed the aggregator; with a lossy channel the
         # record list also holds failed attempts, whose air bytes still
         # count toward the uplink ledger below
@@ -477,16 +468,11 @@ class SpaceRunner:
                     agg_times, d.t_start)
             weights = np.where(active_np,
                                (1.0 + stale) ** (-self.staleness_alpha), 1.0)
-            if trc is None:
+            with obs_span("runner.dispatch"):
                 new_state, _ = round_fn(state, data, jnp.asarray(active_np),
                                         keys[k])
-            else:
-                with trc.span("stage", name="alg.round", round=k,
-                              n_active=int(active_np.sum())):
-                    new_state, _ = round_fn(state, data,
-                                            jnp.asarray(active_np), keys[k])
-            state = _damp_wires(new_state, state, wire_field,
-                                jnp.asarray(weights))
+                state = _damp_wires(new_state, state, wire_field,
+                                    jnp.asarray(weights))
             t0_agg = chunk[0].t_start
             t = chunk[-1].t_done
             agg_times.append(t)

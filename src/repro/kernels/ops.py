@@ -5,10 +5,14 @@ automatically (CPU → interpret=True for validation, TPU → compiled kernel).
 
 Every wrapper is wrapped in a dispatch hook (:func:`_traced`): with an
 active :mod:`repro.obs` tracer each call runs under a
-``jax.profiler.TraceAnnotation`` (so the dispatch shows up named inside
-``jax.profiler.trace`` captures) and records a host-side ``kernel`` span
-(dispatch time — device compute is async and belongs to the profiler).
-Disabled, the hook is one module attribute read and a ``None`` check.
+``jax.profiler.TraceAnnotation`` named ``repro.kernels.<name>`` (so the
+dispatch shows up named inside ``jax.profiler`` captures), records a
+host-side ``kernel`` record and feeds the ``kernel.<name>`` phase of
+:mod:`repro.obs.prof` — all dispatch time: device compute is async, and
+a capture's device plane holds it (the kernel's custom call carries its
+name).  Inside ``jit`` the hook runs once, at trace time.  Disabled, the
+hook is one module attribute read and a ``None`` check, and no span is
+opened.
 """
 from __future__ import annotations
 
@@ -50,16 +54,6 @@ def _traced(fn):
         trc.raw({"kind": "kernel", "name": name,
                  "t_host": t0 - trc._t0_host, "dur_host": dur})
         trc.prof.add("kernel." + name, dur)
-        if trc.prof.sync_device:
-            # honest host/device split: the dispatch above only measures
-            # trace + launch time under JAX's async dispatch; this extra
-            # (opt-in, prof_sync meta) wait attributes device compute
-            t1 = time.perf_counter()
-            jax.block_until_ready(out)
-            dur_sync = time.perf_counter() - t1
-            trc.raw({"kind": "kernel", "name": name + "[device]",
-                     "t_host": t1 - trc._t0_host, "dur_host": dur_sync})
-            trc.prof.add("kernel." + name + "[device]", dur_sync)
         trc.metrics.counter("kernel_dispatches").add(1.0, name=name)
         return out
 

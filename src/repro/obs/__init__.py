@@ -9,12 +9,18 @@ end-of-run aggregates only.
 
 Three layers:
 
-* :mod:`repro.obs.trace` — a zero-overhead-when-disabled :class:`Tracer`
-  of typed event records (round, delivery, ARQ retransmission, cohort,
-  EF revert, kernel dispatch, link-budget sample), emitted by the
-  instrumented engine (both the heapq oracle and the fast batch engine —
-  same schema), ``SpaceRunner``, the channel stack, and
-  :mod:`repro.kernels.ops`;
+* :mod:`repro.obs.trace` — a near-zero-overhead-when-disabled
+  :class:`Tracer` of typed event records (round, delivery, ARQ
+  retransmission, cohort, EF revert, kernel dispatch, link-budget
+  sample), emitted by the instrumented engine (both the heapq oracle and
+  the fast batch engine — same schema), ``SpaceRunner``, the channel
+  stack, and :mod:`repro.kernels.ops`; and :class:`span`, the one host
+  span, which always names its stage in ``jax.profiler`` captures (on
+  the device trace's timeline) and records it only under a Tracer.  The
+  spans: ``runner.round`` (``round=k``), ``runner.schedule``,
+  ``runner.dispatch`` and ``runner.revert`` in ``SpaceRunner``;
+  ``engine.plan_extend``, ``engine.assign``, ``engine.state_build`` and
+  ``engine.event_loop`` in the fast sync engine;
 * :mod:`repro.obs.metrics` — counters/histograms (bytes per link,
   retransmitted bytes, delivery latency, staleness, lost fraction)
   snapshotted into every trace;
@@ -58,9 +64,16 @@ Quickstart::
 Paths ending in ``.gz`` read and write gzip-compressed; long runs can
 stream with bounded memory (``obs.tracing(path, stream_every=N)``).
 
-Disabled (the default) the only cost anywhere in the stack is a module
-attribute read per round / per kernel dispatch — enforced by the gated
-``sim.trace_overhead`` benchmark (<5% enabled, parity disabled).
+Disabled (the default) the cost anywhere in the stack is a module
+attribute read per round / per kernel dispatch, plus one
+``TraceAnnotation`` object per :class:`span` (about a microsecond of
+host time each, some seven a sync round) — enforced by the gated
+``sim.trace_overhead`` benchmark (<5% enabled, parity disabled).  A
+``jax.profiler`` capture of a run shows the spans without a Tracer::
+
+    jax.profiler.start_trace("prof_dir")
+    exp.run(state, data, 10, key)
+    jax.profiler.stop_trace()     # runner.round ⊃ runner.schedule ⊃ engine.*
 """
 from .chrome import chrome_trace, write_chrome_trace
 from .ledger import ingest, load_ledger
@@ -71,10 +84,10 @@ from .prof import (PhaseAcc, attribution, collect, folded, ingest_bench,
 from .report import convgate, render_frontier, render_report, watch
 from .summary import (check, diff, extract_series, render_rounds,
                       summarize, summarize_dict)
-from .trace import (Tracer, active, disable, enable, load, tracing)
+from .trace import (Tracer, active, disable, enable, load, span, tracing)
 
 __all__ = [
-    "Tracer", "active", "enable", "disable", "tracing", "load",
+    "Tracer", "span", "active", "enable", "disable", "tracing", "load",
     "Metrics", "Counter", "Histogram",
     "summarize", "summarize_dict", "extract_series", "render_rounds",
     "diff", "check",

@@ -14,10 +14,10 @@ slower, never *which stage*.  This module rides the existing
   (``window_fit``), channel/ARQ commits (``tx_commit``), batched async
   routing (``dispatch`` / ``window_query``, fast path) and per-dispatch
   route choice (``route``, oracle) — plus kernel dispatches
-  (``kernel.<name>`` leaves via :mod:`repro.kernels.ops`, with an
-  optional ``kernel.<name>[device]`` block-until-ready split when
-  ``sync_device`` is set, so host dispatch and device compute are
-  separated honestly).  Nesting is tracked with an explicit stack, so
+  (``kernel.<name>`` leaves via :mod:`repro.kernels.ops`; device
+  compute is async and is read from a ``jax.profiler`` capture, where
+  the same phases appear as :class:`repro.obs.trace.span` annotations).
+  Nesting is tracked with an explicit stack, so
   each occurrence lands on its full *path* (``event_loop/window_fit``);
   the per-call cost is two ``perf_counter`` reads and a dict update,
   which keeps the whole layer inside the <5% ``sim.trace_overhead``
@@ -87,12 +87,11 @@ class PhaseAcc:
     the next round's nesting.
     """
 
-    __slots__ = ("_stack", "_acc", "sync_device")
+    __slots__ = ("_stack", "_acc")
 
-    def __init__(self, sync_device: bool = False):
+    def __init__(self):
         self._stack: List[tuple] = []     # (path_tuple, t0) frames
         self._acc: Dict[tuple, list] = {}  # path -> [count, total_s]
-        self.sync_device = bool(sync_device)
 
     def begin(self, name: str) -> None:
         st = self._stack

@@ -1,8 +1,9 @@
-"""Structured tracer: typed event records with a zero-cost disabled path.
+"""Structured tracer: typed event records with a near-zero disabled path.
 
 One :class:`Tracer` is active at a time (module global ``TRACER``); hot
 paths read it ONCE per round into a local and branch on ``None`` — the
-entire disabled-mode cost is that attribute read, which is why the
+disabled-mode cost is that attribute read plus the few :class:`span`
+annotations a round opens (below), which is why the
 ``sim.trace_overhead`` bench can show tracing-disabled rounds at parity
 with the pre-instrumentation engine (the existing ``sim.fast_round``
 gates double as the disabled-overhead regression gate: they time the
@@ -16,9 +17,32 @@ mega-1000 traces CI uploads shrink ~20x.  Two clocks coexist:
 
 * **sim time** — event fields named ``t``/``t0``/``t_done`` carry
   simulated seconds (the engine's clock);
-* **host time** — :meth:`Tracer.span` records wall-clock begin/duration
+* **host time** — :class:`span` records wall-clock begin/duration
   (``t_host``/``dur_host`` seconds since tracer start) for stage timings
-  (uplink encode, aggregation, kernel dispatches).
+  (runner stages, kernel dispatches).
+
+Spans on the profiler's clock
+-----------------------------
+
+:class:`span` is the one span primitive.  It always opens a
+``jax.profiler.TraceAnnotation``, so with or without a :class:`Tracer`
+its name (and its keyword arguments, as stats) lands in any
+``jax.profiler`` capture, on the same timeline as the device's
+operations.  With no profiler running and no tracer active a span costs
+one annotation object (about a microsecond of host time) and writes
+nothing.  Only with a :class:`Tracer` active does it also write a
+``stage`` record, or, given ``phase=``, feed the tracer's phase
+profiler (``prof.begin``/``prof.end``) instead.  The spans of the stack:
+
+    ``runner.round``       one sync round of ``SpaceRunner`` (``round=k``)
+    ``runner.schedule``    the engine's call (``run_round``/``run_async``)
+    ``runner.dispatch``    the round's device inputs and the jitted round
+                           (async: with its staleness damping)
+    ``runner.revert``      loss/crash fix-up of the state (lossy or faulty
+                           scenarios only)
+    ``engine.plan_extend`` ``engine.assign`` ``engine.state_build``
+    ``engine.event_loop``  the fast sync engine's top-level phases
+                           (``phase=`` the :mod:`repro.obs.prof` path)
 
 Event kinds emitted by the instrumented stack:
 
@@ -37,7 +61,7 @@ Event kinds emitted by the instrumented stack:
     ``resume``     a crash-consistent restart from a run checkpoint
                    (:mod:`repro.checkpoint.run`)
     ``kernel``     one kernel-dispatch span (repro.kernels.ops)
-    ``span``       generic host-time stage span
+    ``stage``      one :class:`span` (``name`` plus its arguments)
     ``link``       channel link-budget sample (elevation, fade, p_seg)
     ``outage``     blocked-window refresh summary per station
     ``series``     one (name, step, value) time-series sample — the
@@ -71,6 +95,7 @@ from __future__ import annotations
 import contextlib
 import gzip
 import json
+import sys
 import time
 from typing import IO, List, Optional
 
@@ -119,11 +144,8 @@ class Tracer:
         self.events: List[dict] = []
         self.metrics = Metrics()
         # phase-attribution accumulator (repro.obs.prof); the engines
-        # read it once per round alongside active().  prof_sync=True in
-        # the meta additionally times a block-until-ready per kernel
-        # dispatch (honest host/device split; changes timing, not
-        # results — keep it out of gated benches)
-        self.prof = PhaseAcc(sync_device=bool(meta.get("prof_sync")))
+        # read it once per round alongside active()
+        self.prof = PhaseAcc()
         self.path = path
         self.meta = meta
         self.stream_every = stream_every
@@ -165,17 +187,16 @@ class Tracer:
     def host_now(self) -> float:
         return time.perf_counter() - self._t0_host
 
-    @contextlib.contextmanager
-    def span(self, kind: str, **fields):
-        """Host-time stage span: records begin + duration on exit."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            fields["kind"] = kind
-            fields["t_host"] = t0 - self._t0_host
-            fields["dur_host"] = time.perf_counter() - t0
-            self.raw(fields)
+    def span(self, kind: str, **fields) -> "span":
+        """Host-time span recorded as a ``kind`` record on this tracer
+        (begin + duration on exit), under the profiler annotation named
+        ``fields["name"]`` (else ``kind``)."""
+        name = fields.get("name", kind)
+        s = span.__new__(span)
+        s._ann = _annotation(name, {k: v for k, v in fields.items()
+                                    if k != "name"})
+        s._trc, s._phase, s._rec = self, None, dict(fields, kind=kind)
+        return s
 
     # -- output ------------------------------------------------------------
     def _header(self) -> dict:
@@ -251,6 +272,57 @@ class Tracer:
             self._fh = None
             return self.path
         return self.flush()
+
+
+def _annotation(name: str, args: dict):
+    """A ``jax.profiler.TraceAnnotation``, or None where jax is not loaded:
+    the simulator and the obs CLI run without jax, and no profiler can
+    be capturing before it is imported."""
+    jax = sys.modules.get("jax")
+    return None if jax is None else jax.profiler.TraceAnnotation(name, **args)
+
+
+class span:
+    """``with span("runner.dispatch"): ...`` — one host stage, named in
+    ``jax.profiler`` captures and, under an active :class:`Tracer`,
+    recorded as a ``stage`` record (or, with ``phase=``, as a phase of
+    the tracer's :class:`~repro.obs.prof.PhaseAcc`).  Keyword arguments
+    become the annotation's stats and the record's fields."""
+
+    __slots__ = ("_ann", "_trc", "_phase", "_rec", "_t0")
+
+    def __init__(self, name: str, phase: Optional[str] = None, **args):
+        trc = TRACER
+        self._ann = _annotation(name, args)
+        self._trc = trc
+        self._phase = phase
+        self._rec = (None if trc is None or phase is not None
+                     else dict(args, kind="stage", name=name))
+
+    def __enter__(self) -> "span":
+        if self._ann is not None:
+            self._ann.__enter__()
+        trc = self._trc
+        if trc is not None:
+            if self._phase is not None:
+                trc.prof.begin(self._phase)
+            else:
+                self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        trc = self._trc
+        if trc is not None:
+            if self._phase is not None:
+                trc.prof.end()
+            else:
+                t0, rec = self._t0, self._rec
+                rec["t_host"] = t0 - trc._t0_host
+                rec["dur_host"] = time.perf_counter() - t0
+                trc.raw(rec)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
 
 
 def active() -> Optional[Tracer]:

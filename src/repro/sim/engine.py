@@ -45,6 +45,7 @@ import numpy as np
 from ..constellation.links import LinkModel
 from ..constellation.orbits import GroundStation, Walker
 from ..obs.trace import active as _obs_active
+from ..obs.trace import span as obs_span
 from .contacts import ContactPlan
 from .routing import Router
 
@@ -639,15 +640,10 @@ class Engine:
         # one compare and the profiler only times actual extensions
         if t_end <= self.plan.t_start + old:
             return
-        trc = _obs_active()
-        prof = trc.prof if trc is not None else None
-        if prof is not None:
-            prof.begin("plan_extend")
-        self.plan.ensure(t_end)
-        if self.plan.horizon != old:
-            self._refresh_blocked()
-        if prof is not None:
-            prof.end()
+        with obs_span("engine.plan_extend", phase="plan_extend"):
+            self.plan.ensure(t_end)
+            if self.plan.horizon != old:
+                self._refresh_blocked()
 
     def install_channel(self, channel) -> None:
         """Install (or clear) a lossy channel post-construction.

@@ -53,6 +53,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ..obs.trace import active as _obs_active
+from ..obs.trace import span as obs_span
 
 # event kinds (EventQueue.kind values)
 TRAIN = 0       # a satellite finished local training
@@ -310,11 +311,8 @@ def run_round_fast(eng, t0: float, msg_bytes: float):
     trc = _obs_active()
     prof = trc.prof if trc is not None else None
     eng.ensure(t0 + 2 * sc.lookahead)
-    if prof is not None:
-        prof.begin("assign")
-    asg = eng.policy.assign(t0, msg_bytes, eng)
-    if prof is not None:
-        prof.end()
+    with obs_span("engine.assign", phase="assign"):
+        asg = eng.policy.assign(t0, msg_bytes, eng)
     n = sc.walker.n_sats
     scheduled = np.zeros(n, dtype=bool)
     for s in asg.gateways:
@@ -326,11 +324,8 @@ def run_round_fast(eng, t0: float, msg_bytes: float):
                            scheduled, t0)
 
     gs_tx = sc.link.gs_time(msg_bytes)
-    if prof is not None:
-        prof.begin("state_build")
-    cache = eng.chan_cache          # lazily built on the first round
-    if prof is not None:
-        prof.end()
+    with obs_span("engine.state_build", phase="state_build"):
+        cache = eng.chan_cache      # lazily built on the first round
     ev = EventQueue()
     queues = {g: [] for g in asg.gateways}
     busy = {g: False for g in asg.gateways}
@@ -385,31 +380,29 @@ def run_round_fast(eng, t0: float, msg_bytes: float):
         ev.push(t_done, TX_DONE, a=g, b=sat, d=win[2], f=win[0],
                 outcome=outcome)
 
-    if prof is not None:
-        prof.begin("event_loop")
-    while ev:
-        t, i, kind, a, b, _c, d, f = ev.pop()
-        if kind == TRAIN:
-            if a in queues:
-                queues[a].append((t, a))
+    with obs_span("engine.event_loop", phase="event_loop"):
+        while ev:
+            t, i, kind, a, b, _c, d, f = ev.pop()
+            if kind == TRAIN:
+                if a in queues:
+                    queues[a].append((t, a))
+                    try_tx(a, t)
+                else:
+                    r = asg.relays[a]
+                    ev.push(t + r.time, ISL, a=a, b=r.gateway)
+            elif kind == ISL:
+                queues[b].append((t, a))
+                try_tx(b, t)
+            elif kind == TX_START:
                 try_tx(a, t)
-            else:
-                r = asg.relays[a]
-                ev.push(t + r.time, ISL, a=a, b=r.gateway)
-        elif kind == ISL:
-            queues[b].append((t, a))
-            try_tx(b, t)
-        elif kind == TX_START:
-            try_tx(a, t)
-        else:                               # TX_DONE
-            deliveries.append(Delivery(
-                sat=b, t_done=t, t_start=t0, gateway=a,
-                station=d, hops=hops_of.get(b, 0),
-                window=f, **ev.outcomes.pop(i)))
-            busy[a] = False
-            try_tx(a, t)
+            else:                           # TX_DONE
+                deliveries.append(Delivery(
+                    sat=b, t_done=t, t_start=t0, gateway=a,
+                    station=d, hops=hops_of.get(b, 0),
+                    window=f, **ev.outcomes.pop(i)))
+                busy[a] = False
+                try_tx(a, t)
     if prof is not None:
-        prof.end()
         prof.add_many(("event_loop", "window_fit"), pacc[0], pacc[1])
         prof.add_many(("event_loop", "tx_commit"), pacc[2], pacc[3])
 

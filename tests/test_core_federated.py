@@ -129,3 +129,21 @@ def test_led_exact_at_full_participation(problem):
     data, loss, _ = problem
     alg = LED(loss=loss, n_epochs=10, gamma=0.01)
     assert _run_baseline(problem, alg, 600) < 1e-3
+
+
+@pytest.mark.parametrize("fused_uplink", [True, False])
+def test_fedlt_round_names_its_stages(problem, fused_uplink):
+    """The round's stages carry the scopes the device trace's per-layer
+    readers look for, as in ``DeployFedLT.round_step``."""
+    data, loss, _ = problem
+    q = UniformQuantizer(levels=10, vmin=-1, vmax=1, clip=True)
+    alg = FedLT(loss=loss, n_epochs=2, gamma=0.05, rho=0.5,
+                uplink=EFChannel(q), downlink=EFChannel(q),
+                fused_uplink=fused_uplink)
+    st = alg.init(jnp.zeros((D,)), N)
+    text = jax.jit(alg.round).lower(
+        st, data, jnp.ones((N,), bool),
+        jax.random.PRNGKey(1)).as_text(debug_info=True)
+    for scope in ("fedlt.aggregate", "fedlt.downlink", "fedlt.local_train",
+                  "fedlt.uplink"):
+        assert f"/{scope}/" in text, scope

@@ -435,8 +435,8 @@ def test_space_runner_sync_emits_fl_rounds_and_ef_reverts():
     assert obs.check(records) == []
     # host spans for both stages of every round
     stages = of_kind(records, "stage")
-    assert sum(s["name"] == "engine.run_round" for s in stages) == 6
-    assert sum(s["name"] == "alg.round" for s in stages) == 6
+    assert sum(s["name"] == "runner.schedule" for s in stages) == 6
+    assert sum(s["name"] == "runner.dispatch" for s in stages) == 6
 
 
 def test_space_runner_async_emits_staleness():
@@ -451,6 +451,96 @@ def test_space_runner_async_emits_staleness():
     assert m["histograms"]["staleness"]["count"] == \
         sum(r["n_active"] for r in fl)
     assert obs.check(records) == []
+
+
+def _captured_spans(log_dir) -> list:
+    """``(name, start_ns, end_ns, stats)`` of every host event of the
+    ``jax.profiler`` capture written under ``log_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+    [path] = glob.glob(str(log_dir / "**" / "*.xplane.pb"), recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out += [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                         dict(ev.stats)) for ev in line.events]
+    return out
+
+
+def _capture(log_dir):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return jax.profiler.trace(str(log_dir), profiler_options=opts)
+
+
+def test_runner_spans_land_in_a_profiler_capture(tmp_path):
+    """With no Tracer, a sync ``Experiment.run`` under ``jax.profiler``
+    shows one ``runner.round`` per round, carrying its index, around one
+    ``runner.schedule`` (the engine's phases inside it) and one
+    ``runner.dispatch``; and writes no trace record anywhere."""
+    from repro.api import Experiment
+    from repro.core.compression import UniformQuantizer
+    from repro.core.error_feedback import EFChannel
+    from repro.core.fedlt import FedLT
+    from repro.data.logistic import generate, make_local_loss
+    q = UniformQuantizer(levels=10, vmin=-1, vmax=1, clip=True)
+    data, _ = generate(jax.random.PRNGKey(0), n_agents=100, m=16, dim=12)
+    alg = FedLT(loss=make_local_loss(eps=50.0, n_agents=100), n_epochs=1,
+                gamma=0.005, rho=20.0, uplink=EFChannel(q),
+                downlink=EFChannel(q))
+    exp = Experiment("walker-kiruna", alg, compressor=q)
+    st = exp.init(jnp.zeros((12,)), 100)
+    assert obs.active() is None
+    with _capture(tmp_path / "prof"):
+        exp.run(st, data, 3, jax.random.PRNGKey(1)).state.x.block_until_ready()
+    assert obs.active() is None
+    assert not list(tmp_path.rglob("*.jsonl*"))
+    spans = _captured_spans(tmp_path / "prof")
+    rounds = sorted((s for s in spans if s[0] == "runner.round"),
+                    key=lambda s: s[1])
+    assert [s[3].get("round") for s in rounds] == [0, 1, 2]
+
+    def inside(name, outer):
+        return [s for s in spans
+                if s[0] == name and outer[1] <= s[1] and s[2] <= outer[2]]
+
+    for r in rounds:
+        [sched] = inside("runner.schedule", r)
+        [_] = inside("runner.dispatch", r)
+        assert inside("engine.assign", sched)
+        assert inside("engine.event_loop", sched)
+        assert not inside("runner.revert", r)     # a lossless scenario
+
+
+def test_span_records_only_under_a_tracer():
+    with obs.span("work", round=3):
+        pass
+    with obs.tracing() as trc:
+        with obs.span("work", round=3):
+            pass
+        with obs.span("engine.assign", phase="assign"):
+            pass
+        [rec] = trc.events
+        prof = dict(trc.prof._acc)
+    assert rec["kind"] == "stage" and rec["name"] == "work"
+    assert rec["round"] == 3 and rec["dur_host"] >= 0.0
+    # a phase span feeds the phase profiler, not the record stream
+    assert list(prof) == [("assign",)] and prof[("assign",)][0] == 1
+
+
+def test_tracer_span_and_span_share_the_profiler_name(tmp_path):
+    with _capture(tmp_path):
+        with obs.tracing() as trc:
+            with trc.span("stage", name="work", round=1):
+                pass
+            with obs.span("other"):
+                pass
+            names = [r["name"] for r in trc.events]
+    captured = {s[0]: s[3] for s in _captured_spans(tmp_path)}
+    assert names == ["work", "other"]
+    assert captured["work"] == {"round": 1} and "other" in captured
 
 
 def test_kernel_dispatch_events():
