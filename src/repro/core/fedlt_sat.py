@@ -254,20 +254,19 @@ class SpaceRunner:
         channel = getattr(self.engine, "channel", None)
         wire_field = "z_hat" if hasattr(state, "z_hat") else "m_hat"
         has_cache = hasattr(state, "c_up")
-        round_fn = jax.jit(alg.round)
+        dispatch = _round_dispatch(alg, key, n_rounds)
         t, up_bytes, isl_bytes = 0.0, 0.0, 0.0
         logs: List[RoundLog] = []
-        keys = jax.random.split(key, n_rounds)
         trc = _obs_active()       # read once; None ⇒ tracing fully off
         start_k = 0
         if ckpt is not None and resume:
             loaded = ckpt.load(like=state)
             if loaded is not None:
                 # bit-identical continuation: per-round keys come from the
-                # same split above, engine rounds are pure functions of
-                # (scenario, seed, t0), and the time cursor / accumulators
-                # restore exactly — so rounds ≥ start_k replay the
-                # uninterrupted run's floats
+                # same split (_round_dispatch), engine rounds are pure
+                # functions of (scenario, seed, t0), and the time cursor /
+                # accumulators restore exactly — so rounds ≥ start_k replay
+                # the uninterrupted run's floats
                 state, meta = loaded
                 start_k = int(meta.get("k_next", 0))
                 t = float(meta.get("t", 0.0))
@@ -338,8 +337,7 @@ class SpaceRunner:
                 # (the coordinator can only know what actually landed)
                 active_np = attempted if lossy else delivered
                 with obs_span("runner.dispatch"):
-                    state_new, _ = round_fn(state, data, jnp.asarray(active_np),
-                                            keys[k])
+                    state_new, _ = dispatch(state, data, active_np, k)
                 # what each satellite actually put on the air this round — for
                 # lost satellites that is the PRE-revert wire, so cohort byte
                 # accounting below must measure this state, not the final one
@@ -439,7 +437,7 @@ class SpaceRunner:
     # -- buffered-async (FedBuff-style) -------------------------------------
     def _run_async(self, alg, state, data, n_rounds, key, error_fn, log_every):
         msg = self._msg_bytes(state)
-        round_fn = jax.jit(alg.round)
+        dispatch = _round_dispatch(alg, key, n_rounds)
         n_agents = jax.tree_util.tree_leaves(state.x)[0].shape[0]
         wire_field = "z_hat" if hasattr(state, "z_hat") else "m_hat"
 
@@ -455,7 +453,6 @@ class SpaceRunner:
         agg_times: List[float] = []
         logs: List[RoundLog] = []
         up_bytes = 0.0
-        keys = jax.random.split(key, n_rounds)
         for k in range(n_rounds):
             chunk = deliveries[k * self.buffer_size:(k + 1) * self.buffer_size]
             if not chunk:
@@ -469,8 +466,7 @@ class SpaceRunner:
             weights = np.where(active_np,
                                (1.0 + stale) ** (-self.staleness_alpha), 1.0)
             with obs_span("runner.dispatch"):
-                new_state, _ = round_fn(state, data, jnp.asarray(active_np),
-                                        keys[k])
+                new_state, _ = dispatch(state, data, active_np, k)
                 state = _damp_wires(new_state, state, wire_field,
                                     jnp.asarray(weights))
             t0_agg = chunk[0].t_start
@@ -509,6 +505,43 @@ class SpaceRunner:
                 if err is not None and err == err:
                     trc.series("e_K", k, err)
         return state, logs
+
+
+def _round_dispatch(alg, key, n_rounds: int) -> Callable:
+    """``dispatch(state, data, active, k)``: round ``k`` of a run, with
+    ``active`` the host's bool mask and the round's key
+    ``jax.random.split(key, n_rounds)[k]``, bit for bit (resume relies on
+    it).
+
+    The keys come to the host once, before the first round.  Each round
+    then packs its host inputs, the mask (0/1) followed by the key's data,
+    into one ``uint32`` buffer, moves it to the device in one explicit
+    transfer and runs one program, the jitted round, which unpacks it:
+    indexing a device array of keys by a host ``k`` would cost two more
+    programs and two more transfers a round.  Typed keys
+    (``jax.random.key``) travel as their key data and are re-wrapped with
+    their own implementation.
+    """
+    keys = jax.random.split(key, n_rounds)
+    typed = jnp.issubdtype(keys.dtype, jax.dtypes.prng_key)
+    impl = jax.random.key_impl(keys) if typed else None
+    host_keys = np.asarray(jax.random.key_data(keys) if typed else keys)
+    width = host_keys.shape[-1]
+
+    @jax.jit
+    def packed_round(state, data, buf):
+        round_key = buf[-width:]
+        if typed:
+            round_key = jax.random.wrap_key_data(round_key, impl=impl)
+        return alg.round(state, data, buf[:-width] != 0, round_key)
+
+    def dispatch(state, data, active, k: int):
+        buf = np.empty(active.shape[0] + width, np.uint32)
+        buf[:-width] = active
+        buf[-width:] = host_keys[k]
+        return packed_round(state, data, jax.device_put(buf))
+
+    return dispatch
 
 
 def _revert_lost_wires(new_state, old_state, field: str, lost,

@@ -36,8 +36,9 @@ profiler (``prof.begin``/``prof.end``) instead.  The spans of the stack:
 
     ``runner.round``       one sync round of ``SpaceRunner`` (``round=k``)
     ``runner.schedule``    the engine's call (``run_round``/``run_async``)
-    ``runner.dispatch``    the round's device inputs and the jitted round
-                           (async: with its staleness damping)
+    ``runner.dispatch``    the round's host inputs in one transfer and the
+                           jitted round's call (async: with its staleness
+                           damping)
     ``runner.revert``      loss/crash fix-up of the state (lossy or faulty
                            scenarios only)
     ``engine.plan_extend`` ``engine.assign`` ``engine.state_build``
